@@ -43,7 +43,7 @@ def bench_flash(fast):
                               jnp.bfloat16)
         v = jax.random.normal(jax.random.PRNGKey(2), (B, T, Hkv, dh),
                               jnp.bfloat16)
-        got = flash_attention(q, k, v, bq=bq, bk=bk)
+        got = flash_attention(q, k, v, bq=bq, bk=bk, interpret=True)
         want = ref.attention(q, k, v)
         err = float(jnp.abs(got.astype(jnp.float32)
                             - want.astype(jnp.float32)).max())
@@ -90,7 +90,7 @@ def bench_paged_decode(fast):
             table[b, :per] = ids[b * per:(b + 1) * per]
         lens = np.full((B,), live, np.int32)
         got = pk(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                 jnp.asarray(table), jnp.asarray(lens))
+                 jnp.asarray(table), jnp.asarray(lens), interpret=True)
         t = np.minimum(table[0], n_pages - 1)
         k0 = kp[t].reshape(S_max, Hkv, dh)[None, :live]
         v0 = vp[t].reshape(S_max, Hkv, dh)[None, :live]
@@ -117,7 +117,8 @@ def bench_paged_decode(fast):
         kq, ks = kv_quantize(jnp.asarray(kp), jnp.int8)
         vq, vs = kv_quantize(jnp.asarray(vp), jnp.int8)
         got_q = pk(jnp.asarray(q), kq, vq, jnp.asarray(table),
-                   jnp.asarray(lens), k_scale=ks, v_scale=vs)
+                   jnp.asarray(lens), k_scale=ks, v_scale=vs,
+                   interpret=True)
         err_q = float(jnp.abs(got_q[0] - want).max())
         hbm_int8 = B * live * Hkv * (2 * 1 * dh + 2 * 4)  # planes+scales
         report(f"paged_decode int8 B{B} S{S_max} len{live}", flops,
@@ -138,7 +139,8 @@ def bench_distill(fast):
         pseudo = jax.nn.softmax(
             jax.random.normal(jax.random.PRNGKey(2), (n, v)))
         got = float(fused_distill_loss(logits, labels, pseudo,
-                                       jnp.float32(0.5), bn, bv))
+                                       jnp.float32(0.5), bn, bv,
+                                       interpret=True))
         want = float(ref.distill_loss(logits, labels, pseudo, 0.5))
         flops = 6.0 * n * v
         hbm_fused = 2 * 4 * n * v          # one read of logits+pseudo
@@ -159,7 +161,7 @@ def bench_wkv(fast):
         lw = -jnp.exp(mk(3).clip(-3, 1))
         u = mk(4)[:, 0, :, :][0] * 0.3
         s0 = jnp.zeros((B, H, dh, dh))
-        y, sT = wkv6(r, k, v, lw, u, s0, chunk=ch)
+        y, sT = wkv6(r, k, v, lw, u, s0, chunk=ch, interpret=True)
         yr, sr = ref.wkv6(r, k, v, lw, u, s0)
         err = float(jnp.abs(y - yr).max())
         flops = B * H * T * (2 * ch * dh + 4 * dh * dh)
@@ -177,7 +179,7 @@ def bench_ssm(fast):
                                                (B, T, D, N))))
         b = jax.random.normal(jax.random.PRNGKey(1), (B, T, D, N)) * 0.2
         h0 = jnp.zeros((B, D, N))
-        hs, hT = ssm_scan(a, b, h0, chunk=ch, bd=bd)
+        hs, hT = ssm_scan(a, b, h0, chunk=ch, bd=bd, interpret=True)
         hr, hTr = ref.ssm_scan(a, b, h0)
         err = float(jnp.abs(hs - hr).max())
         flops = 3.0 * B * T * D * N

@@ -199,19 +199,18 @@ def bench_ttft(cfg, K, batch, plen, chunk, max_out, repeats, seed=0):
 def bench_mesh(cfg, mesh_arg, K, batch, plen, steps, repeats, seed=0):
     """Member-sharded engine vs single-device: per-device cache bytes,
     tok/s, and token equality.  -> (ok, lines to print)."""
-    mesh = shd.parse_mesh_arg(mesh_arg)
     lines = []
     want_m = int(mesh_arg.lower().split("x")[0]) if "x" in mesh_arg else 1
-    M = 1 if mesh is None else mesh.shape[shd.MEMBER_AXIS]
-    if M < max(want_m, 2):
-        # local_mesh clamps to the devices present, so a 1-device host
-        # yields a 1x1 mesh — running the gate there would "PASS" while
-        # verifying no sharding at all.  Skip loudly instead.
-        return True, [f"mesh: --mesh {mesh_arg} needs {want_m} devices on "
-                      f"the member axis (have {len(jax.devices())}); "
-                      f"skipping the gate "
+    if want_m < 2 or want_m > len(jax.devices()):
+        # a gate without a real member axis would "PASS" while verifying
+        # no sharding at all.  Skip loudly instead.
+        return True, [f"mesh: --mesh {mesh_arg} needs {max(want_m, 2)} "
+                      f"devices on the member axis (have "
+                      f"{len(jax.devices())}); skipping the gate "
                       f"(XLA_FLAGS=--xla_force_host_platform_device_count="
-                      f"{want_m})"]
+                      f"{max(want_m, 2)})"]
+    mesh = shd.parse_mesh_arg(mesh_arg)
+    M = mesh.shape[shd.MEMBER_AXIS]
     params = jax.vmap(lambda k: tf.init(k, cfg))(
         jax.random.split(jax.random.PRNGKey(seed), K))
     prompt = np.asarray(jax.random.randint(

@@ -165,68 +165,31 @@ def _resolve(axis):
 
 
 def ambient_mesh():
-    """The mesh constrain() honors, across jax versions.
-
-    jax >= 0.5 installs an *abstract* mesh via jax.sharding.set_mesh and
-    exposes it with get_abstract_mesh().  jax 0.4.x has neither public
-    API: fall back to the pjit thread-resources mesh that `with mesh:`
-    installs.  Returns None when off-mesh (constrain becomes a no-op).
-    """
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        m = get_am()
-        return None if m is None or m.empty else m
-    from jax._src import mesh as _mesh_lib
-    m = getattr(_mesh_lib, "get_abstract_mesh", lambda: None)()
-    abstract_cls = getattr(jax.sharding, "AbstractMesh", ())
-    if abstract_cls and isinstance(m, abstract_cls):
-        return m
-    env = _mesh_lib.thread_resources.env.physical_mesh
-    return None if env.empty else env
+    """The (abstract) mesh `jax.sharding.set_mesh` installed, or None
+    off-mesh (constrain() then becomes a no-op)."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m
 
 
 def set_mesh(mesh):
-    """Version-portable jax.sharding.set_mesh (context manager).
-
-    On jax 0.4.x a Mesh is itself the context manager that installs the
-    thread-resources env ambient_mesh() falls back to.
-    """
-    sm = getattr(jax.sharding, "set_mesh", None)
-    return sm(mesh) if sm is not None else mesh
+    """jax.sharding.set_mesh: a context manager installing `mesh`."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def make_mesh(axis_shapes, axis_names, auto: bool = True):
-    """Version-portable jax.make_mesh with all-Auto axis types.
-
-    jax >= 0.5 wants explicit axis_types for sharding-in-types; 0.4.x
-    has neither the kwarg nor the enum — plain make_mesh is all-auto.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(axis_shapes, axis_names)
-    kinds = (axis_type.Auto if auto else axis_type.Explicit,)
+    """jax.make_mesh with every axis Auto (or every axis Explicit)."""
+    kind = (jax.sharding.AxisType.Auto if auto
+            else jax.sharding.AxisType.Explicit)
     return jax.make_mesh(axis_shapes, axis_names,
-                         axis_types=kinds * len(axis_names))
+                         axis_types=(kind,) * len(axis_names))
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """Version-portable jax.shard_map.
-
-    `axis_names` lists the MANUAL axes (jax >= 0.6 kwarg); on 0.4.x it
-    maps to `auto` = every mesh axis not named, and check_vma to the old
-    check_rep.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as sm04
-    auto = frozenset() if axis_names is None \
-        else frozenset(mesh.axis_names) - set(axis_names)
-    return sm04(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma, auto=auto)
+    """jax.shard_map; `axis_names` lists the manual axes (default all)."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -290,8 +253,10 @@ def local_mesh(member: int = 1, data: int = 1,
 
 
 def parse_mesh_arg(arg: str):
-    """'MxD' CLI string -> local_mesh(M, D); '' / '1x1' -> None (the
-    unsharded single-device reference path)."""
+    """'MxD' CLI string -> an M x D (member, data) mesh; '' / '1x1' ->
+    None (the unsharded single-device reference path).  Unlike
+    local_mesh this never clamps: asking for more devices than the
+    process holds raises instead of quietly serving on fewer."""
     if not arg or arg.lower() in ("1x1", "none", "off"):
         return None
     try:
@@ -300,17 +265,11 @@ def parse_mesh_arg(arg: str):
         raise ValueError(f"--mesh wants 'MxD' (e.g. 2x1), got {arg!r}")
     if m * d <= 1:
         return None
+    n = len(jax.devices())
+    if m * d > n:
+        raise ValueError(f"--mesh {arg} needs {m * d} devices, this "
+                         f"process has {n}")
     return local_mesh(m, d)
-
-
-def axis_size(axis: str) -> int:
-    """Version-portable jax.lax.axis_size inside shard_map/pmap bodies.
-
-    0.4.x predates lax.axis_size; psum of a unit constant is the classic
-    idiom and constant-folds to a Python int.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    return fn(axis) if fn is not None else jax.lax.psum(1, axis)
 
 
 def mesh_axis_size(axis: str) -> int:

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.common.sharding import axis_size as _axis_size
 from repro.common.sharding import shard_map as _shard_map
 from repro.common.types import ECConfig
 from repro.core import compression as comp
@@ -79,7 +78,7 @@ def allgather_relabel(stacked_params, batches, logits_fn: Callable,
 def _ring_body(local_params, local_batch, logits_fn, ec: ECConfig,
                axis: str, quorum=None, n_vocab_shards: int = 1):
     """Runs on one shard of the ensemble axis. Leading local dim = 1."""
-    K = _axis_size(axis)
+    K = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     perm = [(i, (i + 1) % K) for i in range(K)]
 

@@ -6,6 +6,8 @@
   ssm_scan         Mamba selective scan, chunk-sequential
 
 Each kernel has a pure-jnp oracle in ref.py; ops.py is the dispatch layer
-model code imports.  Kernels are validated with interpret=True on CPU and
-target TPU (pl.pallas_call + BlockSpec VMEM tiling) for deployment.
+model code imports.  Every kernel defaults to interpret=False (compiled
+for the TPU); CPU tests pass interpret=True explicitly, and
+tests/test_tpu_compile.py compiles the main-path kernels for a described
+v5e chip at real widths.
 """

@@ -96,7 +96,7 @@ def _pad_to(x, mult, axis, value=0.0):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def fused_distill_loss(logits, labels, pseudo, lam,
-                       bn=DEFAULT_BN, bv=DEFAULT_BV, interpret=True):
+                       bn=DEFAULT_BN, bv=DEFAULT_BV, interpret=False):
     loss, _ = _fwd(logits, labels, pseudo, lam, bn, bv, interpret)
     return loss
 

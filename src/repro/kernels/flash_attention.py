@@ -92,7 +92,7 @@ def _pad_axis(x, mult, axis):
                                              "bq", "bk", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     scale: float | None = None, bq: int = DEFAULT_BQ,
-                    bk: int = DEFAULT_BK, interpret: bool = True):
+                    bk: int = DEFAULT_BK, interpret: bool = False):
     """q: (B,T,H,dh), k/v: (B,S,Hkv,dh) -> (B,T,H,dh)."""
     B, T, H, dh = q.shape
     S, Hkv = k.shape[1], k.shape[2]

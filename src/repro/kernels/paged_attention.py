@@ -73,26 +73,22 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         k = k_ref[0, 0].astype(jnp.float32)      # (page, dk)
         v = v_ref[0, 0].astype(jnp.float32)      # (page, dv)
         g = q.shape[0]
+        dk = k.shape[1]
+        s = jax.lax.dot_general(q[:, :dk], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
         if quant:
-            # per-token absmax scales ride next to the page: dequant in
-            # VMEM right after the (cheap) quantized DMA
-            k = k * ks_ref[0, 0][:, None]        # (page,) -> column bcast
-            v = v * vs_ref[0, 0][:, None]
+            # per-token absmax scales ride next to the page as a (1, page)
+            # row; a token's scale factors out of its score and its value
+            # row, so dequant is one multiply per score, not per element
+            s = s * ks_ref[0, 0]
         if extra:
             # unquantized extra key features (absorbed-MLA rope keys):
             # score = q_main . k_deq + q_extra . k_extra
-            dk = k.shape[1]
-            s = jax.lax.dot_general(
-                q[:, :dk], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
             s = s + jax.lax.dot_general(
                 q[:, dk:], ke_ref[0, 0].astype(jnp.float32),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            s = s * scale
-        else:
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
+        s = s * scale
         k_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (g, page), 1)
         ok = k_pos < length
         if window > 0:
@@ -102,6 +98,8 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         alpha = jnp.exp(m_s[:] - m_new)
         p = jnp.exp(s - m_new[:, None])
         l_s[:] = l_s[:] * alpha + p.sum(axis=1)
+        if quant:
+            p = p * vs_ref[0, 0]                 # p @ (v * s) == (p * s) @ v
         acc_s[:] = acc_s[:] * alpha[:, None] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -116,15 +114,16 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
 def paged_attention(q, k_pages, v_pages, table, lens, window: int = 0,
                     scale: float | None = None, k_scale=None, v_scale=None,
-                    k_extra=None, interpret: bool = True):
+                    k_extra=None, interpret: bool = False):
     """q: (B, H, dk); k_pages: (n_pages, page, Hkv, dk); v_pages:
     (n_pages, page, Hkv, dv); table: (B, P) int32 (>= n_pages means
     unallocated); lens: (B,) int32 valid entries -> (B, H, dv).
 
     Quantized pools pass k_scale/v_scale (n_pages, page, Hkv) per-token
     absmax scales; each scale page is a tiny extra input block indexed
-    by the SAME table lookup as its plane, so dequant (value * scale)
-    happens in VMEM after the ~4x-smaller quantized DMA.  k_extra
+    by the SAME table lookup as its plane, and dequant happens in VMEM
+    after the ~4x-smaller quantized DMA (a token's scale multiplies its
+    score and its softmax weight).  k_extra
     (n_pages, page, Hkv, dr) is an unquantized extra key block
     (absorbed-MLA rope keys); q then carries dk + dr features and the
     score is the sum of the two dots.  All three default to None ==
@@ -154,7 +153,7 @@ def paged_attention(q, k_pages, v_pages, table, lens, window: int = 0,
 
     def scale_index(bh, j, table_ref, lens_ref):
         phys, h, _, _ = kv_index(bh, j, table_ref, lens_ref)
-        return (phys, h, 0)
+        return (phys, h, 0, 0)
 
     def q_index(bh, j, table_ref, lens_ref):
         return (bh // Hkv, bh % Hkv, 0, 0)
@@ -166,10 +165,13 @@ def paged_attention(q, k_pages, v_pages, table, lens, window: int = 0,
     ]
     operands = [q2, kp, vp]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, page), scale_index),
-                     pl.BlockSpec((1, 1, page), scale_index)]
-        operands += [k_scale.transpose(0, 2, 1),      # (n_pages, Hkv, page)
-                     v_scale.transpose(0, 2, 1)]
+        # scales as (n_pages, Hkv, 1, page): the unit axis makes the
+        # block's last two dims (1, page) equal the array's, which the
+        # TPU tiling rule needs of a block narrower than (8, 128)
+        in_specs += [pl.BlockSpec((1, 1, 1, page), scale_index),
+                     pl.BlockSpec((1, 1, 1, page), scale_index)]
+        operands += [k_scale.transpose(0, 2, 1)[:, :, None, :],
+                     v_scale.transpose(0, 2, 1)[:, :, None, :]]
     if extra:
         dr = k_extra.shape[-1]
         in_specs += [pl.BlockSpec((1, 1, page, dr), kv_index)]

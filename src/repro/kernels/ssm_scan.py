@@ -50,7 +50,7 @@ def _scan_kernel(a_ref, b_ref, h0_ref, hs_ref, hT_ref, h_s):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "bd", "interpret"))
 def ssm_scan(a, b, h0, chunk: int = DEFAULT_CHUNK, bd: int = DEFAULT_BD,
-             interpret: bool = True):
+             interpret: bool = False):
     """a/b: (B,T,D,N) f32, h0: (B,D,N) -> (hs (B,T,D,N), h_T (B,D,N))."""
     B, T, D, N = a.shape
     ch = min(chunk, T)

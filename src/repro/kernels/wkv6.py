@@ -77,7 +77,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv6(r, k, v, log_w, u, s0, chunk: int = DEFAULT_CHUNK,
-         interpret: bool = True):
+         interpret: bool = False):
     """r/k/v/log_w: (B,T,H,dh) f32; u: (H,dh); s0: (B,H,dh,dh).
     -> (y (B,T,H,dh), s_T (B,H,dh,dh))."""
     B, T, H, dh = r.shape

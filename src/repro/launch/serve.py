@@ -337,6 +337,7 @@ def main():
     args = ap.parse_args()
 
     from repro.common import sharding as shd
+    from repro.common.compile_cache import use_compile_cache
     from repro.configs import registry
     from repro.models import transformer as tf
     from repro.serving import EnsembleEngine, client
@@ -346,6 +347,7 @@ def main():
         # fleet mode: the replica PROCESSES build the engines; the
         # parent never initializes params at all
         return serve_fleet(args, cfg)
+    use_compile_cache()
     key = jax.random.PRNGKey(args.seed)
     K = args.members if args.ensemble else 1
     params = jax.vmap(lambda k: tf.init(k, cfg))(jax.random.split(key, K))

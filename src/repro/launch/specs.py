@@ -125,8 +125,6 @@ def cache_pspecs(cfg: ModelConfig, cache: Any, par: ParallelConfig,
     shard their channel dim; batch always over the batch role axes.
     """
     ba = tuple(par.batch_axes) or None
-    if ba is not None and len(ba) == 1:
-        ba = ba[0]  # jax 0.4.x PartitionSpec doesn't canonicalize ('x',)
     msize = mesh.shape[par.model_axis]
 
     def rule(path, leaf):

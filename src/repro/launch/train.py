@@ -16,7 +16,8 @@ import jax
 import numpy as np
 
 
-def main():
+def main(argv=None) -> int:
+    """Run the rounds; -> 0, or 1 if any round's loss is not finite."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper_nin")
     ap.add_argument("--reduced", action="store_true",
@@ -42,14 +43,16 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--straggler-drop", type=int, default=0,
                     help="simulate N lagging members dropped per round")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    from repro.common.compile_cache import use_compile_cache
     from repro.common.types import ECConfig
     from repro.configs import registry
     from repro.data import image_member_datasets, lm_member_datasets
     from repro.optim import adamw, sgd_momentum
     from repro.runtime.trainer import Trainer
 
+    use_compile_cache()
     cfg = registry.get_config(args.arch, reduced=args.reduced)
     key = jax.random.PRNGKey(args.seed)
     rng = np.random.default_rng(args.seed)
@@ -73,6 +76,7 @@ def main():
     if args.resume and tr.resume():
         print(f"resumed from round {tr.round}")
 
+    diverged = []
     for r in range(tr.round, args.rounds):
         mask = None
         if args.straggler_drop:
@@ -87,11 +91,16 @@ def main():
               f"{ev['local_loss']:.4f} err {ev['local_err']:.4f} | "
               f"{'ens' if args.aggregator == 'ec' else 'global'} nll "
               f"{ev['global_loss']:.4f} err {ev['global_err']:.4f}")
+        if not np.isfinite([loss, ev["local_loss"], ev["global_loss"]]).all():
+            diverged.append(r)
     tr.save()
     if tr.ckpt:
         tr.ckpt.close()
     best, k = tr.best_member()
     print(f"final model: member {k} (EC-DNN_L rule)")
+    if diverged:
+        print(f"non-finite loss in round(s) {diverged}")
+        return 1
     return 0
 
 

@@ -540,15 +540,6 @@ _INT8_MAX = 127.0
 _FP8_MAX = 448.0  # float8_e4m3fn finite max
 
 
-def fp8_dtype():
-    dt = getattr(jnp, "float8_e4m3fn", None)
-    if dt is None:
-        raise ValueError("kv_dtype='fp8' needs jax.numpy.float8_e4m3fn, "
-                         "which this platform's jax does not provide — "
-                         "use 'int8'")
-    return dt
-
-
 def kv_quantized(kv_dtype: str) -> bool:
     return kv_dtype in ("int8", "fp8")
 
@@ -563,7 +554,7 @@ def kv_storage_dtype(kv_dtype: str, dtype):
     if kv_dtype == "int8":
         return jnp.int8
     if kv_dtype == "fp8":
-        return fp8_dtype()
+        return jnp.float8_e4m3fn
     raise ValueError(f"kv_dtype must be one of {KV_DTYPES}, "
                      f"got {kv_dtype!r}")
 

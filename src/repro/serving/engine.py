@@ -157,8 +157,6 @@ class EnsembleEngine:
                 "kv_dtype != f32 requires paged=True (only paged planes "
                 "are stored quantized; the contiguous pool is the "
                 "bit-exact reference)")
-        if kv_dtype == "fp8":
-            attn_mod.fp8_dtype()  # raises if this jax has no float8
         self.kv_dtype = kv_dtype
         # swap_params validates incoming RAW trees against the raw spec
         # captured here, BEFORE any absorbed-MLA leaves are added

@@ -40,6 +40,7 @@ the wire from /healthz page accounting).
 """
 from __future__ import annotations
 
+import glob
 import http.client
 import json
 import os
@@ -56,6 +57,45 @@ from repro.serving import client as sclient
 from repro.serving import obs as obs_mod
 
 _READY = "REPLICA_READY"
+
+# TPU chips on the PCI bus (Google's vendor id; device ids v3 .. tpu7x)
+_TPU_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = frozenset({"0x0027", "0x0056", "0x005e", "0x0062",
+                              "0x0063", "0x006f", "0x0076"})
+_TPU_PORT_BASE = 8472  # one runtime port per child, past libtpu's 8471
+
+
+def tpu_chips() -> List[int]:
+    """The TPU chips this process may hand out, found WITHOUT loading
+    JAX (a supervisor that loaded the TPU runtime would itself hold the
+    chips its children need): those TPU_VISIBLE_CHIPS names when it is
+    set, else every TPU chip on the PCI bus."""
+    visible = os.environ.get("TPU_VISIBLE_CHIPS", "").strip()
+    if visible:
+        return [int(c) for c in visible.split(",")]
+    n = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        device = os.path.join(os.path.dirname(vendor), "device")
+        try:
+            with open(vendor) as v, open(device) as d:
+                n += (v.read().strip() == _TPU_PCI_VENDOR
+                      and d.read().strip() in _TPU_PCI_DEVICES)
+        except OSError:
+            continue
+    return list(range(n))
+
+
+def chip_env(chip: int) -> Dict[str, str]:
+    """The libtpu environment that gives one process chip `chip` alone:
+    per-process bounds smaller than the host's let several processes
+    load the TPU runtime at once, TPU_VISIBLE_CHIPS names the chip, and
+    each runtime listens on a port of its own."""
+    port = _TPU_PORT_BASE + chip
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}"}
 
 
 # -- the spec: everything a process needs to rebuild the engine ---------------
@@ -196,9 +236,10 @@ def _make_admin_swap(spec: EngineSpec, router):
 def main(argv: Optional[List[str]] = None) -> int:
     """Run ONE replica process: engine + scheduler loop + HTTP surface.
 
-    Prints "REPLICA_READY <port>" on stdout once the engine's kernels
-    are compiled and the port is bound — the supervisor's spawn
-    handshake.  SIGTERM drains gracefully (in-flight requests finish,
+    Prints "REPLICA_READY <port> <platform>" on stdout once the
+    engine's kernels are compiled and the port is bound — the
+    supervisor's spawn handshake, naming the device the engine runs
+    on.  SIGTERM drains gracefully (in-flight requests finish,
     pages return to the pool) and exits 0; SIGKILL is the fault the
     soak harness injects.
     """
@@ -221,9 +262,13 @@ def main(argv: Optional[List[str]] = None) -> int:
             raw = f.read()
     spec = EngineSpec.from_json(raw)
 
+    import jax
+
+    from repro.common.compile_cache import use_compile_cache
     from repro.serving.frontend.router import Replica, Router
     from repro.serving.frontend.server import FrontendServer
 
+    use_compile_cache()
     engine = spec.build_engine()
     # compile BOTH kernels before declaring ready: the supervisor's
     # handshake must mean "this port serves at decode speed", not
@@ -247,7 +292,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     done = threading.Event()
     signal.signal(signal.SIGTERM, lambda *_: done.set())
     signal.signal(signal.SIGINT, lambda *_: done.set())
-    print(f"{_READY} {srv.port}", flush=True)
+    print(f"{_READY} {srv.port} {jax.devices()[0].platform}", flush=True)
     while not done.wait(0.2):
         pass
     srv.shutdown(drain=True)
@@ -278,17 +323,23 @@ class ReplicaProcess:
     drain -> exit 0), kill() the fault-injection one (SIGKILL, no
     drain, no goodbye).  `tail` keeps the child's last output lines
     for crash diagnostics.
+
+    chip: the TPU chip this child holds alone (chip_env), or None on a
+    host without TPU chips.  A pinned child that reports any platform
+    but "tpu" in its handshake is refused: killed, never ready.
     """
 
     def __init__(self, name: str, spec: EngineSpec,
                  host: str = "127.0.0.1",
                  max_queue_depth: Optional[int] = None,
-                 verbose: bool = False):
+                 verbose: bool = False, chip: Optional[int] = None):
         self.name = name
         self.spec = spec
         self.host = host
         self.max_queue_depth = max_queue_depth
         self.verbose = verbose
+        self.chip = chip
+        self.platform: Optional[str] = None
         self.port: Optional[int] = None
         self.proc: Optional[subprocess.Popen] = None
         self.tail: deque = deque(maxlen=80)
@@ -305,16 +356,23 @@ class ReplicaProcess:
             cmd += ["--max-queue-depth", str(self.max_queue_depth)]
         if self.verbose:
             cmd += ["--verbose"]
-        env = dict(os.environ, PYTHONPATH=_src_pythonpath())
-        self.port = None
+        self.port = self.platform = None
         self._ready.clear()
         self.proc = subprocess.Popen(
-            cmd, env=env, stdout=subprocess.PIPE,
+            cmd, env=self.env(), stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT, text=True)
         self._reader = threading.Thread(
             target=self._read_stdout, name=f"replica-io-{self.name}",
             daemon=True)
         self._reader.start()
+
+    def env(self) -> Dict[str, str]:
+        """The child's environment: the parent's, the repo on
+        PYTHONPATH, and its chip pinning when it holds one."""
+        env = dict(os.environ, PYTHONPATH=_src_pythonpath())
+        if self.chip is not None:
+            env.update(chip_env(self.chip))
+        return env
 
     def _read_stdout(self):
         proc = self.proc
@@ -322,16 +380,24 @@ class ReplicaProcess:
             line = line.rstrip("\n")
             self.tail.append(line)
             if line.startswith(_READY):
-                self.port = int(line.split()[1])
+                _, port, self.platform = line.split()
+                self.port = int(port)
                 self._ready.set()
         proc.stdout.close()
 
     def wait_ready(self, timeout: float = 300.0) -> bool:
         """Block until the ready handshake (kernels compiled, port
-        bound) or child death; False on timeout/death."""
+        bound) or child death; False on timeout/death, and False for a
+        chip-pinned child that came up off the TPU (it is killed)."""
         deadline = time.time() + timeout
         while time.time() <= deadline:
             if self._ready.wait(0.1):
+                if self.chip is not None and self.platform != "tpu":
+                    self.kill()
+                    self.tail.append(
+                        f"refused: pinned to TPU chip {self.chip} but "
+                        f"came up on {self.platform}")
+                    return False
                 return True
             if self.proc is None or self.proc.poll() is not None:
                 return False
@@ -392,6 +458,10 @@ class FleetRouter:
     POST /admin/swap, routes ~25% of generate() calls at it until
     `canary_requests` complete, then swaps the rest — the in-process
     canary semantics, spoken over sockets.
+
+    On a host with TPU chips every replica holds one chip of its own:
+    the fleet refuses more replicas than chips, and refuses a child
+    that came up on another platform (ReplicaProcess.wait_ready).
     """
 
     def __init__(self, spec: EngineSpec, n: int = 2,
@@ -404,8 +474,10 @@ class FleetRouter:
         self.host = host
         self.max_queue_depth = max_queue_depth
         self.verbose = verbose
-        self.procs: List[ReplicaProcess] = [
-            self._new_proc(f"p{i}") for i in range(n)]
+        self.chips = tpu_chips()
+        self.procs: List[ReplicaProcess] = []
+        self.procs = [self._new_proc(f"p{i}", chip)
+                      for i, chip in enumerate(self._take_chips(n))]
         self._lock = threading.Lock()
         self._in_flight: Dict[str, int] = {p.name: 0 for p in self.procs}
         self._next_id = n
@@ -424,10 +496,25 @@ class FleetRouter:
         self.traces = obs_mod.TraceRing(keep=256)
         self._next_trace = 0
 
-    def _new_proc(self, name: str) -> ReplicaProcess:
+    def _new_proc(self, name: str, chip: Optional[int]) -> ReplicaProcess:
         return ReplicaProcess(name, self.spec, host=self.host,
                               max_queue_depth=self.max_queue_depth,
-                              verbose=self.verbose)
+                              verbose=self.verbose, chip=chip)
+
+    def _take_chips(self, k: int) -> List[Optional[int]]:
+        """k chips that no running (or not yet started) replica holds,
+        lowest first; k Nones on a host without TPU chips."""
+        if not self.chips:
+            return [None] * k
+        held = {p.chip for p in self.procs
+                if p.proc is None or p.proc.poll() is None}
+        free = [c for c in self.chips if c not in held]
+        if len(free) < k:
+            raise ValueError(
+                f"{k} more replica(s) need a TPU chip each, but only "
+                f"{len(free)} of this host's {len(self.chips)} chips are "
+                f"free")
+        return free[:k]
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -585,7 +672,7 @@ class FleetRouter:
         idx = next(i for i, p in enumerate(self.procs) if p.name == name)
         old = self.procs[idx]
         old.terminate(timeout=10.0)
-        fresh = self._new_proc(name)
+        fresh = self._new_proc(name, self._take_chips(1)[0])
         fresh.start()
         if not fresh.wait_ready(timeout):
             tail = "\n".join(fresh.tail)
@@ -608,8 +695,8 @@ class FleetRouter:
         if n > len(live):
             fresh = []
             with self._lock:
-                for _ in range(n - len(live)):
-                    p = self._new_proc(f"p{self._next_id}")
+                for chip in self._take_chips(n - len(live)):
+                    p = self._new_proc(f"p{self._next_id}", chip)
                     self._next_id += 1
                     fresh.append(p)
             for p in fresh:

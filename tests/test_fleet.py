@@ -598,3 +598,61 @@ def test_fleet_429_over_sockets():
         assert fleet.n_backoffs >= 1
     finally:
         fleet.stop()
+
+
+# -- one chip per child on a TPU host -----------------------------------------
+
+
+def test_fleet_pins_one_chip_per_child(monkeypatch):
+    """On a 4-chip host each child's environment names a chip of its
+    own (and a runtime port of its own); a fifth replica is refused,
+    at construction and at scale-out alike.  A host without chips
+    leaves the children's environment unpinned."""
+    from repro.serving.frontend import replica as replica_mod
+    monkeypatch.setattr(replica_mod, "tpu_chips", lambda: [0, 1, 2, 3])
+    fleet = FleetRouter(FLEET_SPEC, n=4)
+    envs = [p.env() for p in fleet.procs]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert "src" in e["PYTHONPATH"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    for e in envs:
+        port = e["TPU_PROCESS_PORT"]
+        assert e["TPU_PROCESS_ADDRESSES"] == f"localhost:{port}"
+    with pytest.raises(ValueError, match="need a TPU chip each"):
+        FleetRouter(FLEET_SPEC, n=5)
+    with pytest.raises(ValueError, match="0 of this host's 4 chips"):
+        fleet.scale_to(5)
+
+    monkeypatch.setattr(replica_mod, "tpu_chips", lambda: [])
+    cpu = FleetRouter(FLEET_SPEC, n=5)
+    assert all(p.chip is None for p in cpu.procs)
+    assert "TPU_VISIBLE_CHIPS" not in cpu.procs[0].env()
+
+
+def test_tpu_chips_honors_the_chips_this_process_was_given(monkeypatch):
+    """A supervisor that was itself handed a subset of the host's chips
+    hands out only those, whatever the PCI bus shows."""
+    from repro.serving.frontend import replica as replica_mod
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2,3")
+    assert replica_mod.tpu_chips() == [2, 3]
+    fleet = FleetRouter(FLEET_SPEC, n=2)
+    assert [p.chip for p in fleet.procs] == [2, 3]
+    with pytest.raises(ValueError, match="need a TPU chip each"):
+        FleetRouter(FLEET_SPEC, n=3)
+
+
+def test_fleet_refuses_child_that_came_up_off_the_chip(monkeypatch):
+    """A child pinned to a chip that reports another platform in its
+    ready handshake (here: the CPU, as a child whose chip the parent
+    holds can) is killed and the fleet refuses to start."""
+    from repro.serving.frontend import replica as replica_mod
+    monkeypatch.setattr(replica_mod, "tpu_chips", lambda: [0])
+    spec = dataclasses.replace(FLEET_SPEC, prefix_cache=False, mesh="")
+    fleet = FleetRouter(spec, n=1)
+    with pytest.raises(RuntimeError, match="came up on cpu"):
+        fleet.start(timeout=600.0)
+    assert fleet.procs[0].platform == "cpu"
+    assert not fleet.procs[0].alive

@@ -35,7 +35,7 @@ def test_flash_attention_sweep(shape, dtype, causal, window):
     kk = jax.random.normal(jax.random.PRNGKey(1), (B, S, Hkv, dh), dtype)
     vv = jax.random.normal(jax.random.PRNGKey(2), (B, S, Hkv, dh), dtype)
     got = flash_attention(q, kk, vv, causal=causal, window=window,
-                          bq=32, bk=32)
+                          bq=32, bk=32, interpret=True)
     want = ref.attention(q, kk, vv, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **tol(dtype))
@@ -51,7 +51,8 @@ def test_distill_loss_sweep(n, v, bn, bv, dtype):
     pseudo = jax.nn.softmax(
         jax.random.normal(jax.random.PRNGKey(2), (n, v))).astype(dtype)
     lam = jnp.float32(0.4)
-    got = fused_distill_loss(logits, labels, pseudo, lam, bn, bv)
+    got = fused_distill_loss(logits, labels, pseudo, lam, bn, bv,
+                             interpret=True)
     want = ref.distill_loss(logits, labels, pseudo, lam)
     np.testing.assert_allclose(float(got), float(want), rtol=3e-3)
 
@@ -62,8 +63,8 @@ def test_distill_loss_grad_matches():
     labels = jax.random.randint(jax.random.PRNGKey(1), (n,), 0, v)
     pseudo = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(2), (n, v)))
     lam = jnp.float32(0.8)
-    gf = jax.grad(lambda z: fused_distill_loss(z, labels, pseudo, lam))(
-        logits)
+    gf = jax.grad(lambda z: fused_distill_loss(z, labels, pseudo, lam,
+                                                interpret=True))(logits)
     gr = jax.grad(lambda z: ref.distill_loss(z, labels, pseudo, lam))(logits)
     np.testing.assert_allclose(np.asarray(gf), np.asarray(gr), atol=1e-6)
 
@@ -80,7 +81,8 @@ def test_wkv6_sweep(shape, chunk):
     lw = -jnp.exp(mk(4).clip(-3, 2))  # strong + weak decays
     u = jax.random.normal(jax.random.PRNGKey(5), (H, dh)) * 0.3
     s0 = jax.random.normal(jax.random.PRNGKey(6), (B, H, dh, dh)) * 0.1
-    y_got, s_got = wkv6(r, kk, vv, lw, u, s0, chunk=chunk)
+    y_got, s_got = wkv6(r, kk, vv, lw, u, s0, chunk=chunk,
+                        interpret=True)
     y_ref, s_ref = ref.wkv6(r, kk, vv, lw, u, s0)
     np.testing.assert_allclose(np.asarray(y_got), np.asarray(y_ref),
                                atol=5e-4, rtol=1e-3)
@@ -97,7 +99,8 @@ def test_ssm_scan_sweep(shape, chunk, bd):
     a = jnp.exp(-jnp.abs(jax.random.normal(k, (B, T, D, N))))
     b = jax.random.normal(jax.random.PRNGKey(1), (B, T, D, N)) * 0.2
     h0 = jax.random.normal(jax.random.PRNGKey(2), (B, D, N)) * 0.1
-    hs_got, hT_got = ssm_scan(a, b, h0, chunk=chunk, bd=bd)
+    hs_got, hT_got = ssm_scan(a, b, h0, chunk=chunk, bd=bd,
+                              interpret=True)
     hs_ref, hT_ref = ref.ssm_scan(a, b, h0)
     np.testing.assert_allclose(np.asarray(hs_got), np.asarray(hs_ref),
                                atol=1e-5, rtol=1e-5)

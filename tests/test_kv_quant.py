@@ -39,8 +39,7 @@ from repro.configs import registry
 from repro.kernels import ref
 from repro.kernels import paged_attention as pk
 from repro.models import transformer as tf
-from repro.models.attention import (KV_DTYPES, fp8_dtype, kv_dequantize,
-                                    kv_quantize)
+from repro.models.attention import KV_DTYPES, kv_dequantize, kv_quantize
 from repro.serving import EnsembleEngine, kv_cache
 
 GQA = registry.get_config("deepseek-7b", reduced=True).with_(
@@ -63,14 +62,6 @@ def _prompts(cfg):
 
 
 _KW = dict(n_slots=4, max_prompt=12, max_out=8, prefill_chunk=4)
-
-
-def _has_fp8():
-    try:
-        fp8_dtype()
-        return True
-    except ValueError:
-        return False
 
 
 @pytest.fixture(scope="module")
@@ -98,10 +89,8 @@ def test_int8_roundtrip_error_bound():
 
 
 def test_fp8_roundtrip_error_bound():
-    if not _has_fp8():
-        pytest.skip("no float8_e4m3fn in this jax")
     v = jax.random.normal(jax.random.PRNGKey(1), (32, 4, 16), jnp.float32)
-    q, s = kv_quantize(v, fp8_dtype())
+    q, s = kv_quantize(v, jnp.float8_e4m3fn)
     d = kv_dequantize(q, s)
     amax = np.abs(np.asarray(v)).max(-1, keepdims=True)
     # e4m3 keeps ~4 bits of mantissa headroom at the top of the range
@@ -277,8 +266,6 @@ def test_int8_quality_bounded_vs_f32_reference(arch, contig_ref):
 
 
 def test_fp8_quality_bounded(contig_ref):
-    if not _has_fp8():
-        pytest.skip("no float8_e4m3fn in this jax")
     got = EnsembleEngine(GQA, _params(GQA), paged=True, page_size=4,
                          kv_dtype="fp8", **_KW).generate(_prompts(GQA),
                                                          max_new=8)

@@ -63,11 +63,12 @@ def test_parse_mesh_arg():
     assert shd.parse_mesh_arg("1x1") is None
     with pytest.raises(ValueError, match="MxD"):
         shd.parse_mesh_arg("two-by-one")
-    m = shd.parse_mesh_arg("2x1")
     if MULTI:
-        assert m.shape[shd.MEMBER_AXIS] == 2
+        assert shd.parse_mesh_arg("2x1").shape[shd.MEMBER_AXIS] == 2
     else:
-        assert m is None or m.shape[shd.MEMBER_AXIS] == 1
+        # never a silent clamp onto the one device present
+        with pytest.raises(ValueError, match="needs 2 devices"):
+            shd.parse_mesh_arg("2x1")
 
 
 def test_member_pspecs_shard_leading_axis_only():

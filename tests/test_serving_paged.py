@@ -80,7 +80,7 @@ def test_paged_kernel_matches_ref_attention(name, H, Hkv, dk, dv, window):
     kp, vp, table, gk, gv = _paged_case(B, S_max, lens, page, Hkv, dk, dv)
     got = paged_attention(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
                           jnp.asarray(table), jnp.asarray(lens, jnp.int32),
-                          window=window)
+                          window=window, interpret=True)
     for b in range(B):
         L = int(lens[b])
         qf = np.zeros((1, L, H, dk), np.float32)
@@ -102,7 +102,8 @@ def test_paged_kernel_matches_gather_oracle():
     kp, vp, table, _, _ = _paged_case(B, S_max, lens, page, Hkv, dk, dv,
                                       seed=4)
     got = paged_attention(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                          jnp.asarray(table), jnp.asarray(lens, jnp.int32))
+                          jnp.asarray(table), jnp.asarray(lens, jnp.int32),
+                          interpret=True)
     want = ref.paged_attention(jnp.asarray(q), jnp.asarray(kp),
                                jnp.asarray(vp), jnp.asarray(table),
                                jnp.asarray(lens, jnp.int32))
