@@ -127,6 +127,7 @@ def _parts(logits, labels, pseudo, bn, bv, interpret):
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bn,), jnp.float32)] * 4,
         interpret=interpret,
+        name="distill_loss_fwd",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(y2, z2, p2)
@@ -169,6 +170,7 @@ def _bwd(bn, bv, interpret, res, g):
         out_specs=pl.BlockSpec((bn_, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Np, Vp), logits.dtype),
         interpret=interpret,
+        name="distill_loss_bwd",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(y2p, lse_p, gcoef, z2p, p2p)

@@ -196,6 +196,7 @@ def paged_attention(q, k_pages, v_pages, table, lens, window: int = 0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, dv), q.dtype),
         interpret=interpret,
+        name="paged_attention",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(table.astype(jnp.int32), lens.astype(jnp.int32), *operands)

@@ -85,12 +85,17 @@ def main(argv=None) -> int:
                               replace=False)
             mask[drop] = 0.0
             print(f"round {r}: dropping stragglers {sorted(drop)}")
+        before = dict(tr.counters)
         loss = tr.run_round(straggler_mask=mask)
         ev = tr.evaluate()
+        d = {k: v - before.get(k, 0) for k, v in tr.counters.items()}
+        traces = sum(v for k, v in d.items() if k.startswith("trace."))
         print(f"round {r:3d} | train {loss:.4f} | local nll "
               f"{ev['local_loss']:.4f} err {ev['local_err']:.4f} | "
               f"{'ens' if args.aggregator == 'ec' else 'global'} nll "
-              f"{ev['global_loss']:.4f} err {ev['global_err']:.4f}")
+              f"{ev['global_loss']:.4f} err {ev['global_err']:.4f} | "
+              f"steps {d['local_steps']} distill {d['distill_steps']} "
+              f"traces {traces}")
         if not np.isfinite([loss, ev["local_loss"], ev["global_loss"]]).all():
             diverged.append(r)
     tr.save()

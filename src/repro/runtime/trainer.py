@@ -18,6 +18,16 @@ atomic, keep-N); `Trainer.resume()` restores the newest committed round.
 Straggler policy: at aggregation time members listed as lagging are
 excluded from the ensemble via the quorum mask (renormalized 1/(K-r));
 MA mode uses the same mask for the parameter mean.
+
+Tracing: every program has a stable name (XLA modules `jit_ec_local_step`,
+`jit_ec_distill_step`, `jit_ec_sync_step`, `jit_ec_ma_step`,
+`jit_ec_relabel`), and `run_round` divides the round into host spans that
+do not nest (`jax.profiler.TraceAnnotation`, written into the profiler's
+own trace and costing about a microsecond when no profiler runs):
+`ec.sample`, `ec.step`, `ec.loss_readback`, `ec.relabel`, `ec.ma`,
+`ec.checkpoint`.  Each trace of a program runs its Python body once,
+under an `ec.trace.<program>` span, and counts `trace.<program>` in
+`Trainer.counters`, beside `local_steps` and `distill_steps`.
 """
 from __future__ import annotations
 
@@ -69,6 +79,9 @@ class Trainer:
         self.ckpt = CheckpointManager(ckpt_dir, keep=3) if ckpt_dir else None
         self.pseudo_buffer = None  # (subset_batch, pseudo_targets)
         self.round = 0
+        # steps dispatched, and traces of each program ("trace.<name>")
+        self.counters: Dict[str, int] = {"local_steps": 0,
+                                         "distill_steps": 0}
 
         keys = jax.random.split(key, self.K)
         params = jax.vmap(lambda k: models.init(k, cfg))(keys)
@@ -87,6 +100,14 @@ class Trainer:
     def _member_loss(self, params, batch, pseudo, lam):
         return steps.make_member_loss(self.cfg)(params, batch, pseudo, lam)
 
+    def _traced(self, program: str):
+        """Count one trace of `program` and span it in the profiler's
+        trace.  Called from a jitted function's Python body, so it runs
+        once per (re)trace, never per call."""
+        key = "trace." + program
+        self.counters[key] = self.counters.get(key, 0) + 1
+        return jax.profiler.TraceAnnotation("ec.trace." + program)
+
     def _build_steps(self):
         opt = self.opt
         plain = steps.make_local_step(self.cfg, opt,
@@ -94,16 +115,27 @@ class Trainer:
         syncs = steps.make_local_step(self.cfg, opt,
                                       grad_accum=self.grad_accum, sync=True)
 
-        self._plain_step = jax.jit(
-            lambda s, b: plain(s, b, None, 0.0), donate_argnums=(0,))
-        self._sync_step = jax.jit(
-            lambda s, b: syncs(s, b, None, 0.0), donate_argnums=(0,))
-        self._distill_step = jax.jit(
-            lambda s, b, ps, lam: plain(s, b, ps, lam),
-            donate_argnums=(0,))
-        self._ma_step = jax.jit(
-            lambda s, q: {"params": agg.ma_aggregate(s["params"], q),
-                          "opt": s["opt"]})
+        def ec_local_step(s, b):
+            with self._traced("ec_local_step"):
+                return plain(s, b, None, 0.0)
+
+        def ec_sync_step(s, b):
+            with self._traced("ec_sync_step"):
+                return syncs(s, b, None, 0.0)
+
+        def ec_distill_step(s, b, ps, lam):
+            with self._traced("ec_distill_step"):
+                return plain(s, b, ps, lam)
+
+        def ec_ma_step(s, q):
+            with self._traced("ec_ma_step"):
+                return {"params": agg.ma_aggregate(s["params"], q),
+                        "opt": s["opt"]}
+
+        self._plain_step = jax.jit(ec_local_step, donate_argnums=(0,))
+        self._sync_step = jax.jit(ec_sync_step, donate_argnums=(0,))
+        self._distill_step = jax.jit(ec_distill_step, donate_argnums=(0,))
+        self._ma_step = jax.jit(ec_ma_step)
 
         def eval_members(params, batch):
             with layout_ctx(batch=()):
@@ -130,22 +162,33 @@ class Trainer:
     # aggregation
     # ------------------------------------------------------------------
 
+    def _relabel_program(self, quorum=None):
+        """The jitted Eqn-6 relabel of the allgather protocol, built
+        afresh on every call."""
+        def ec_relabel(p, b):
+            with self._traced("ec_relabel"):
+                return agg.allgather_relabel(p, b, self._logits, self.ec,
+                                             quorum=quorum)
+
+        return jax.jit(ec_relabel)
+
     def _relabel(self, quorum=None):
         """Relabel relabel_fraction of each member's shard -> pseudo buffer."""
-        subset, _ = sample_relabel_subset(self.rng, self.shards,
-                                          self.ec.relabel_fraction)
-        logits_fn = lambda p, b: self._logits(p, b)  # noqa: E731
-        if self.mesh is not None and self.ec.protocol == "ring" \
-                and self.K > 1:
-            pseudo = agg.ring_relabel(self.mesh, self.state["params"],
-                                      subset, logits_fn, self.ec,
-                                      axis=self.ec_axis(), quorum=quorum)
-        else:
-            pseudo = jax.jit(
-                lambda p, b: agg.allgather_relabel(p, b, logits_fn, self.ec,
-                                                   quorum=quorum))(
-                self.state["params"], subset)
-        self.pseudo_buffer = (subset, pseudo)
+        with jax.profiler.TraceAnnotation("ec.relabel",
+                                          round=self.round) as span:
+            subset, _ = sample_relabel_subset(self.rng, self.shards,
+                                              self.ec.relabel_fraction)
+            span.set_metadata(images=int(np.prod(
+                jax.tree.leaves(subset)[0].shape[:2])))
+            if self.mesh is not None and self.ec.protocol == "ring" \
+                    and self.K > 1:
+                pseudo = agg.ring_relabel(self.mesh, self.state["params"],
+                                          subset, self._logits, self.ec,
+                                          axis=self.ec_axis(), quorum=quorum)
+            else:
+                pseudo = self._relabel_program(quorum)(
+                    self.state["params"], subset)
+            self.pseudo_buffer = (subset, pseudo)
 
     def ec_axis(self) -> str:
         return "data"
@@ -158,18 +201,28 @@ class Trainer:
         """One full round: tau local steps (first p mixed if a pseudo
         buffer exists), then aggregation per the configured method."""
         ec = self.ec
+        r = self.round
         for t in range(ec.tau):
             if ec.aggregator == "ec" and self.pseudo_buffer is not None \
                     and t < ec.p_steps:
                 lam = distill.lam_schedule(t, ec.lam, ec.p_steps)
-                batch, pseudo = self._sample_pseudo_batch()
-                self.state, loss = self._distill_step(
-                    self.state, batch, pseudo, lam)
+                with jax.profiler.TraceAnnotation("ec.sample", round=r, t=t):
+                    batch, pseudo = self._sample_pseudo_batch()
+                with jax.profiler.TraceAnnotation("ec.step", round=r, t=t,
+                                                  kind="distill"):
+                    self.state, loss = self._distill_step(
+                        self.state, batch, pseudo, lam)
+                self.counters["distill_steps"] += 1
             else:
-                batch = sample_batch(self.rng, self.shards, self.batch)
-                step = self._sync_step if ec.aggregator == "sync" \
-                    else self._plain_step
-                self.state, loss = step(self.state, batch)
+                with jax.profiler.TraceAnnotation("ec.sample", round=r, t=t):
+                    batch = sample_batch(self.rng, self.shards, self.batch)
+                sync = ec.aggregator == "sync"
+                step = self._sync_step if sync else self._plain_step
+                with jax.profiler.TraceAnnotation(
+                        "ec.step", round=r, t=t,
+                        kind="sync" if sync else "local"):
+                    self.state, loss = step(self.state, batch)
+                self.counters["local_steps"] += 1
 
         quorum = None
         if straggler_mask is not None:
@@ -177,11 +230,15 @@ class Trainer:
         if ec.aggregator == "ec":
             self._relabel(quorum)
         elif ec.aggregator == "ma":
-            self.state = self._ma_step(self.state, quorum)
+            with jax.profiler.TraceAnnotation("ec.ma", round=r):
+                self.state = self._ma_step(self.state, quorum)
         self.round += 1
         if self.ckpt is not None:
-            self.ckpt.save(self.round, self.state)
-        return float(loss)
+            with jax.profiler.TraceAnnotation("ec.checkpoint", round=r):
+                self.ckpt.save(self.round, self.state)
+        with jax.profiler.TraceAnnotation("ec.loss_readback", round=r):
+            loss = float(loss)
+        return loss
 
     def _sample_pseudo_batch(self):
         subset, pseudo = self.pseudo_buffer

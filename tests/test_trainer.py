@@ -145,3 +145,84 @@ def test_best_member_selection():
     assert 0 <= k < 3
     assert jax.tree.leaves(best)[0].shape \
         == jax.tree.leaves(tr.state["params"])[0].shape[1:]
+
+
+# -- program names, host spans and retrace counts ----------------------------
+
+
+def _lowered(tr, program):
+    """Lower one of the trainer's programs on arguments of its shapes."""
+    from repro.data import sample_batch, sample_relabel_subset
+    batch = sample_batch(tr.rng, tr.shards, tr.batch)
+    if program == "ec_local_step":
+        return tr._plain_step.lower(tr.state, batch)
+    if program == "ec_sync_step":
+        return tr._sync_step.lower(tr.state, batch)
+    if program == "ec_distill_step":
+        V = tr.cfg.vocab_size
+        pseudo = jnp.full((tr.K, tr.batch, V), 1.0 / V)
+        return tr._distill_step.lower(tr.state, batch, pseudo, 0.5)
+    if program == "ec_ma_step":
+        return tr._ma_step.lower(tr.state, None)
+    subset, _ = sample_relabel_subset(tr.rng, tr.shards, 0.5)
+    return tr._relabel_program().lower(tr.state["params"], subset)
+
+
+@pytest.mark.parametrize("program", ["ec_local_step", "ec_sync_step",
+                                     "ec_distill_step", "ec_ma_step",
+                                     "ec_relabel"])
+def test_program_module_names(program):
+    tr = _cnn_trainer("ec", K=2, tau=2)
+    text = _lowered(tr, program).as_text()
+    assert f"module @jit_{program} " in text, text[:200]
+    assert "jit__lambda" not in text
+
+
+def _traced_rounds(tr, rounds, trace_dir):
+    """Run `rounds` rounds under the profiler (no Python tracer) ->
+    [(name, stats)] of the host events whose names start with "ec."."""
+    import glob
+    import os
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        for _ in range(rounds):
+            tr.run_round()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(ev.name, dict(ev.stats)) for plane in pd.planes
+            if plane.name == "/host:CPU" for line in plane.lines
+            for ev in line.events if ev.name.startswith("ec.")]
+
+
+def test_round_phases_leave_host_spans(tmp_path):
+    tau = 4
+    tr = _cnn_trainer("ec", K=2, tau=tau)
+    spans = _traced_rounds(tr, 2, tmp_path)
+    names = [n for n, _ in spans]
+    assert names.count("ec.sample") == names.count("ec.step") == tau * 2
+    assert names.count("ec.loss_readback") == 2
+    assert names.count("ec.relabel") == 2
+    kinds = sorted(st["kind"] for n, st in spans if n == "ec.step")
+    # round 2 distils its first p = tau / 2 steps from round 1's relabel
+    assert kinds == ["distill"] * (tau // 2) + ["local"] * (tau * 3 // 2)
+    rounds = sorted({st["round"] for n, st in spans if n == "ec.step"})
+    assert rounds == [0, 1]
+    images = {st["images"] for n, st in spans if n == "ec.relabel"}
+    assert images == {2 * 32}  # K x half of 64 images a member
+
+
+def test_retrace_counters_match_the_trace(tmp_path):
+    tr = _cnn_trainer("ec", K=2, tau=4)
+    spans = _traced_rounds(tr, 3, tmp_path)
+    names = [n for n, _ in spans]
+    c = tr.counters
+    assert c["trace.ec_local_step"] == 1
+    assert c["trace.ec_distill_step"] == 1
+    assert c["trace.ec_relabel"] == names.count("ec.trace.ec_relabel") >= 1
+    assert names.count("ec.trace.ec_local_step") == 1
+    assert c["local_steps"] == 4 + 2 + 2 and c["distill_steps"] == 2 + 2
