@@ -19,6 +19,7 @@ from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import compression as comp
 
@@ -30,6 +31,16 @@ def lam_schedule(step_in_round: jax.Array, lam0: float,
         return jnp.zeros_like(jnp.asarray(step_in_round, jnp.float32))
     frac = 1.0 - jnp.asarray(step_in_round, jnp.float32) / p_steps
     return lam0 * jnp.clip(frac, 0.0, 1.0)
+
+
+def lam_host(step_in_round: int, lam0: float, p_steps: int) -> np.float32:
+    """`lam_schedule` worked out on the host in float32, op for op, so
+    a step gets its lambda without a device program."""
+    f32 = np.float32
+    if p_steps <= 0:
+        return f32(0)
+    frac = f32(1) - f32(step_in_round) / f32(p_steps)
+    return f32(lam0) * np.clip(frac, f32(0), f32(1))
 
 
 def pseudo_ce_dense(logits: jax.Array, pseudo_probs: jax.Array) -> jax.Array:

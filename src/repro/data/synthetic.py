@@ -102,12 +102,32 @@ def lm_member_datasets(key, n_members: int, per_member: int, seq_len: int,
 # sampling
 # ---------------------------------------------------------------------------
 
+def batch_indices(rng: np.random.Generator, tree, batch: int) -> np.ndarray:
+    """(K, batch) row indices into a member-stacked tree: same batch
+    size, independent indices, drawn with replacement."""
+    K, n = jax.tree.leaves(tree)[0].shape[:2]
+    return rng.integers(0, n, size=(K, batch))
+
+
+def relabel_indices(rng: np.random.Generator, tree,
+                    fraction: float) -> np.ndarray:
+    """(K, m) row indices, m = `fraction` of each member's n rows, drawn
+    without replacement."""
+    K, n = jax.tree.leaves(tree)[0].shape[:2]
+    m = max(1, int(n * fraction))
+    return np.stack([rng.permutation(n)[:m] for _ in range(K)])
+
+
+def gather_members(tree, idx):
+    """out[k] = a[k, idx[k]] for every leaf a of a member-stacked tree.
+    Traceable: under one jit a whole batch is one program."""
+    take = jax.vmap(lambda a, i: a[i])
+    return jax.tree.map(lambda a: take(a, idx), tree)
+
+
 def sample_batch(rng: np.random.Generator, shards: dict, batch: int) -> dict:
     """Per-member minibatch: same batch size, independent indices."""
-    K, n = jax.tree.leaves(shards)[0].shape[:2]
-    idx = rng.integers(0, n, size=(K, batch))
-    rows = np.arange(K)[:, None]
-    return jax.tree.map(lambda a: a[rows, idx], shards)
+    return gather_members(shards, batch_indices(rng, shards, batch))
 
 
 def sample_relabel_subset(rng: np.random.Generator, shards: dict,
@@ -115,9 +135,5 @@ def sample_relabel_subset(rng: np.random.Generator, shards: dict,
     """The paper relabels a fraction of D_k (70% default). Returns the
     subset and the indices (so the distill phase can pair pseudo-labels
     with true labels)."""
-    K, n = jax.tree.leaves(shards)[0].shape[:2]
-    m = max(1, int(n * fraction))
-    idx = np.stack([rng.permutation(n)[:m] for _ in range(K)])
-    rows = np.arange(K)[:, None]
-    subset = jax.tree.map(lambda a: a[rows, idx], shards)
-    return subset, idx
+    idx = relabel_indices(rng, shards, fraction)
+    return gather_members(shards, idx), idx

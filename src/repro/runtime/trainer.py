@@ -19,15 +19,20 @@ Straggler policy: at aggregation time members listed as lagging are
 excluded from the ensemble via the quorum mask (renormalized 1/(K-r));
 MA mode uses the same mask for the parameter mean.
 
+Inputs: a step's batch is drawn as indices on the host (`self.rng`) and
+gathered from the member shards (or the pseudo buffer) by one program,
+`jit_ec_sample`; lambda is worked out on the host (`distill.lam_host`).
+
 Tracing: every program has a stable name (XLA modules `jit_ec_local_step`,
 `jit_ec_distill_step`, `jit_ec_sync_step`, `jit_ec_ma_step`,
-`jit_ec_relabel`), and `run_round` divides the round into host spans that
-do not nest (`jax.profiler.TraceAnnotation`, written into the profiler's
-own trace and costing about a microsecond when no profiler runs):
-`ec.sample`, `ec.step`, `ec.loss_readback`, `ec.relabel`, `ec.ma`,
-`ec.checkpoint`.  Each trace of a program runs its Python body once,
-under an `ec.trace.<program>` span, and counts `trace.<program>` in
-`Trainer.counters`, beside `local_steps` and `distill_steps`.
+`jit_ec_relabel`, `jit_ec_sample`), and `run_round` divides the round
+into host spans that do not nest (`jax.profiler.TraceAnnotation`,
+written into the profiler's own trace and costing about a microsecond
+when no profiler runs): `ec.sample` (one index draw and one
+`jit_ec_sample` dispatch), `ec.step`, `ec.loss_readback`, `ec.relabel`,
+`ec.ma`, `ec.checkpoint`.  Each trace of a program runs its Python body
+once, under an `ec.trace.<program>` span, and counts `trace.<program>`
+in `Trainer.counters`, beside `local_steps` and `distill_steps`.
 """
 from __future__ import annotations
 
@@ -42,10 +47,10 @@ from repro import models
 from repro.common.sharding import layout_ctx
 from repro.common.types import ECConfig, ModelConfig
 from repro.core import aggregation as agg
-from repro.core import compression as comp
 from repro.core import distill
 from repro.core import ensemble as ens
-from repro.data import sample_batch, sample_relabel_subset
+from repro.data import (batch_indices, gather_members, relabel_indices,
+                        sample_batch)
 from repro.checkpoint import CheckpointManager
 from repro.optim import Optimizer
 from repro.runtime import steps
@@ -137,6 +142,14 @@ class Trainer:
         self._distill_step = jax.jit(ec_distill_step, donate_argnums=(0,))
         self._ma_step = jax.jit(ec_ma_step)
 
+        def ec_sample(tree, idx):
+            with self._traced("ec_sample"):
+                return gather_members(tree, idx)
+
+        # the shards and the pseudo buffer are arguments: closed over,
+        # they would be baked into the program as constants
+        self._sample = jax.jit(ec_sample)
+
         def eval_members(params, batch):
             with layout_ctx(batch=()):
                 logits = jax.vmap(lambda p: self._logits(p, batch))(params)
@@ -176,8 +189,8 @@ class Trainer:
         """Relabel relabel_fraction of each member's shard -> pseudo buffer."""
         with jax.profiler.TraceAnnotation("ec.relabel",
                                           round=self.round) as span:
-            subset, _ = sample_relabel_subset(self.rng, self.shards,
-                                              self.ec.relabel_fraction)
+            subset = self._sample(self.shards, relabel_indices(
+                self.rng, self.shards, self.ec.relabel_fraction))
             span.set_metadata(images=int(np.prod(
                 jax.tree.leaves(subset)[0].shape[:2])))
             if self.mesh is not None and self.ec.protocol == "ring" \
@@ -205,7 +218,7 @@ class Trainer:
         for t in range(ec.tau):
             if ec.aggregator == "ec" and self.pseudo_buffer is not None \
                     and t < ec.p_steps:
-                lam = distill.lam_schedule(t, ec.lam, ec.p_steps)
+                lam = distill.lam_host(t, ec.lam, ec.p_steps)
                 with jax.profiler.TraceAnnotation("ec.sample", round=r, t=t):
                     batch, pseudo = self._sample_pseudo_batch()
                 with jax.profiler.TraceAnnotation("ec.step", round=r, t=t,
@@ -215,7 +228,8 @@ class Trainer:
                 self.counters["distill_steps"] += 1
             else:
                 with jax.profiler.TraceAnnotation("ec.sample", round=r, t=t):
-                    batch = sample_batch(self.rng, self.shards, self.batch)
+                    batch = self._sample(self.shards, batch_indices(
+                        self.rng, self.shards, self.batch))
                 sync = ec.aggregator == "sync"
                 step = self._sync_step if sync else self._plain_step
                 with jax.profiler.TraceAnnotation(
@@ -241,18 +255,10 @@ class Trainer:
         return loss
 
     def _sample_pseudo_batch(self):
-        subset, pseudo = self.pseudo_buffer
-        n = jax.tree.leaves(subset)[0].shape[1]
-        idx = self.rng.integers(0, n, size=(self.K, self.batch))
-        rows = np.arange(self.K)[:, None]
-        batch = jax.tree.map(lambda a: a[rows, idx], subset)
-        take = lambda a: a[rows, idx]  # noqa: E731
-        if isinstance(pseudo, comp.TopM):
-            ps = comp.TopM(take(pseudo.vals), take(pseudo.idx),
-                           take(pseudo.rest))
-        else:
-            ps = take(pseudo)
-        return batch, ps
+        """-> (batch, pseudo targets) at the same rows, in one gather
+        (a `TopM` target is a pytree like the batch)."""
+        idx = batch_indices(self.rng, self.pseudo_buffer[0], self.batch)
+        return self._sample(self.pseudo_buffer, idx)
 
     # ------------------------------------------------------------------
     # evaluation / reporting (paper Figures 1-3, Table 1)

@@ -2,9 +2,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.data import (image_member_datasets, lm_member_datasets,
-                        sample_batch, sample_relabel_subset)
+from repro.data import (gather_members, image_member_datasets,
+                        lm_member_datasets, sample_batch,
+                        sample_relabel_subset)
 
 
 def test_deterministic():
@@ -68,3 +70,47 @@ def test_sampling_shapes():
     assert sub["images"].shape == (3, 16, 8, 8, 3)
     # indices unique per member (sampling without replacement)
     assert all(len(set(row)) == len(row) for row in idx)
+
+
+def _eager_take(tree, idx):
+    """The eager advanced-indexing draw: a[arange(K)[:, None], idx]."""
+    rows = np.arange(idx.shape[0])[:, None]
+    return jax.tree.map(lambda a: np.asarray(a)[rows, idx], tree)
+
+
+@pytest.mark.parametrize("data", ["image", "lm"])
+@pytest.mark.parametrize("draw", ["batch", "relabel_subset"])
+def test_draws_equal_eager_gather_bit_for_bit(draw, data):
+    """Indices from the rng as drawn before, rows gathered exactly: the
+    draws equal the eager advanced-indexing gather on the same rng state,
+    and leave the rng where it left it."""
+    k = jax.random.PRNGKey(4)
+    if data == "image":
+        train, _ = image_member_datasets(k, 3, 40, n_classes=5, img=8)
+    else:
+        train, _ = lm_member_datasets(k, 3, 40, 12, 50)
+    K, n = jax.tree.leaves(train)[0].shape[:2]
+    rng, ref_rng = np.random.default_rng(11), np.random.default_rng(11)
+    if draw == "batch":
+        got = sample_batch(rng, train, 7)
+        idx = ref_rng.integers(0, n, size=(K, 7))
+    else:
+        got, got_idx = sample_relabel_subset(rng, train, 0.7)
+        idx = np.stack([ref_rng.permutation(n)[:28] for _ in range(K)])
+        np.testing.assert_array_equal(got_idx, idx)
+    want = _eager_take(train, idx)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g = np.asarray(g)
+        assert g.dtype == w.dtype and g.tobytes() == w.tobytes()
+    assert rng.integers(0, 2 ** 31) == ref_rng.integers(0, 2 ** 31)
+
+
+def test_gather_members_jitted_equals_eager():
+    k = jax.random.PRNGKey(5)
+    train, _ = image_member_datasets(k, 2, 16, n_classes=3, img=4)
+    idx = np.random.default_rng(0).integers(0, 16, size=(2, 5))
+    got = jax.jit(gather_members)(train, idx)
+    want = _eager_take(train, idx)
+    for name in ("images", "labels"):
+        assert np.asarray(got[name]).tobytes() == want[name].tobytes()

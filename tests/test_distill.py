@@ -16,6 +16,15 @@ def test_lam_schedule_anneals_to_zero():
     assert all(a >= b for a, b in zip(lams, lams[1:]))
 
 
+@pytest.mark.parametrize("p_steps", [10, 195])
+def test_lam_host_equals_lam_schedule_bit_for_bit(p_steps):
+    for t in range(p_steps + 2):
+        got = distill.lam_host(t, 0.5, p_steps)
+        want = np.asarray(distill.lam_schedule(t, 0.5, p_steps))
+        assert isinstance(got, np.float32) and want.dtype == np.float32
+        assert got.tobytes() == want.tobytes(), (t, got, want)
+
+
 def _setup(n=12, v=50):
     k = jax.random.PRNGKey(0)
     logits = jax.random.normal(k, (n, v)) * 2

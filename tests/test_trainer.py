@@ -164,13 +164,16 @@ def _lowered(tr, program):
         return tr._distill_step.lower(tr.state, batch, pseudo, 0.5)
     if program == "ec_ma_step":
         return tr._ma_step.lower(tr.state, None)
+    if program == "ec_sample":
+        return tr._sample.lower(tr.shards, np.zeros((tr.K, tr.batch),
+                                                    np.int32))
     subset, _ = sample_relabel_subset(tr.rng, tr.shards, 0.5)
     return tr._relabel_program().lower(tr.state["params"], subset)
 
 
 @pytest.mark.parametrize("program", ["ec_local_step", "ec_sync_step",
                                      "ec_distill_step", "ec_ma_step",
-                                     "ec_relabel"])
+                                     "ec_relabel", "ec_sample"])
 def test_program_module_names(program):
     tr = _cnn_trainer("ec", K=2, tau=2)
     text = _lowered(tr, program).as_text()
@@ -226,3 +229,88 @@ def test_retrace_counters_match_the_trace(tmp_path):
     assert c["trace.ec_relabel"] == names.count("ec.trace.ec_relabel") >= 1
     assert names.count("ec.trace.ec_local_step") == 1
     assert c["local_steps"] == 4 + 2 + 2 and c["distill_steps"] == 2 + 2
+
+
+# -- the batch draw: host indices, one gather program ------------------------
+
+
+def _eager_take(tree, idx):
+    rows = np.arange(idx.shape[0])[:, None]
+    return jax.tree.map(lambda a: np.asarray(a)[rows, idx], tree)
+
+
+def _assert_bits_equal(got, want):
+    got = jax.device_get(got)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g = np.asarray(g)
+        assert g.dtype == w.dtype and g.tobytes() == w.tobytes()
+
+
+@pytest.mark.parametrize("draw", ["local", "relabel_subset", "pseudo_dense",
+                                  "pseudo_topk"])
+def test_trainer_draws_equal_eager_gather(draw):
+    """Every draw of a round gathers the rows the rng gives, bit for bit
+    as the eager a[arange(K)[:, None], idx], in the rng's call order."""
+    import copy
+    tau = 4
+    mode = "topk" if draw == "pseudo_topk" else "dense"
+    tr = _cnn_trainer("ec", K=2, tau=tau, label_mode=mode)
+    K, n = jax.tree.leaves(tr.shards)[0].shape[:2]
+    ref_rng = copy.deepcopy(tr.rng)
+    seen = []
+    step = tr._plain_step
+
+    def recording_step(state, batch):
+        seen.append(jax.device_get(batch))
+        return step(state, batch)
+    tr._plain_step = recording_step
+    tr.run_round()
+    idx = [ref_rng.integers(0, n, size=(K, tr.batch)) for _ in range(tau)]
+    if draw == "local":
+        assert len(seen) == tau
+        for got, i in zip(seen, idx):
+            _assert_bits_equal(got, _eager_take(tr.shards, i))
+        return
+    m = int(n * tr.ec.relabel_fraction)
+    sub_idx = np.stack([ref_rng.permutation(n)[:m] for _ in range(K)])
+    if draw == "relabel_subset":
+        _assert_bits_equal(tr.pseudo_buffer[0],
+                           _eager_take(tr.shards, sub_idx))
+        return
+    from repro.core.compression import TopM
+    assert isinstance(tr.pseudo_buffer[1], TopM) == (mode == "topk")
+    buf = jax.device_get(tr.pseudo_buffer)
+    got = tr._sample_pseudo_batch()
+    _assert_bits_equal(got, _eager_take(
+        buf, ref_rng.integers(0, m, size=(K, tr.batch))))
+
+
+def test_sample_program_traces_once_per_input():
+    """Three inputs (the local batch, the relabel subset, the pseudo
+    batch with its targets), traced in the first two rounds and never
+    again, while the relabel's jit is built anew every round."""
+    tr = _cnn_trainer("ec", K=2, tau=4)
+    got = []
+    for _ in range(4):
+        tr.run_round()
+        got.append((tr.counters["trace.ec_sample"],
+                    tr.counters["trace.ec_relabel"]))
+    assert got == [(2, 1), (3, 2), (3, 3), (3, 4)]
+
+
+def test_distill_step_program_same_for_host_lambda():
+    """lambda as np.float32 from the host lowers to the program the
+    device scalar of `lam_schedule` lowered to."""
+    from repro.core import distill
+    tr = _cnn_trainer("ec", K=2, tau=4)
+    batch = tr._sample(tr.shards, np.zeros((tr.K, tr.batch), np.int32))
+    V = tr.cfg.vocab_size
+    pseudo = jnp.full((tr.K, tr.batch, V), 1.0 / V)
+    host = distill.lam_host(1, 0.5, 2)
+    dev = distill.lam_schedule(1, 0.5, 2)
+    assert host.tobytes() == np.asarray(dev).tobytes()
+    text = [tr._distill_step.lower(tr.state, batch, pseudo, lam).as_text()
+            for lam in (host, dev)]
+    assert "module @jit_ec_distill_step" in text[0]
+    assert text[0] == text[1]
