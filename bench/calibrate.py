@@ -60,10 +60,10 @@ def serving(ctx, args):
         srv.close()
 
 
-def training(ctx, args):
+def training(ctx, args, drv):
     import gc
 
-    from harness import ec, faults
+    from harness import faults
     for seed in args.seeds:
         ctx.seed = seed
         runs = [""]
@@ -71,18 +71,17 @@ def training(ctx, args):
             runs += [f for f in faults.EC_FAULTS if f != "state_unchanged"]
         for fault in runs:
             t0 = time.time()
-            tr, recs = ec.trainer_for(ctx, fault)
-            for _ in range(ctx.mix["warm_rounds"]):
-                tr.run_round()
+            tr, recs = drv.trainer_for(ctx, fault)
+            losses = [tr.run_round() for _ in range(ctx.mix["warm_rounds"])]
             for r in recs.values():
                 r.release()
             del tr
             gc.collect()
-            r = ec.readings(ctx.config, seed, recs)
+            r = drv.readings(ctx.config, seed, recs)
             emit(args.out, seed=seed, run=fault or "program",
-                 seconds=time.time() - t0, **r)
+                 seconds=time.time() - t0, round_losses=losses, **r)
             if not fault and seed in args.control_seeds:
-                r = ec.readings(ctx.config, seed, recs, precision="fp8")
+                r = drv.readings(ctx.config, seed, recs, precision="fp8")
                 emit(args.out, seed=seed, run="control_fp8", **r)
 
 
@@ -107,10 +106,11 @@ def main(argv=None) -> int:
     ctx = cells.load(ROOT, args.workload)
     ctx.seconds, ctx.t_start, ctx.seed = args.seconds, time.time(), \
         args.seeds[0]
-    if cells.RUNNERS[ctx.config["kind"]] == "serve":
+    drv = cells.runner(ctx)
+    if drv.CALIBRATE == "serving":
         serving(ctx, args)
     else:
-        training(ctx, args)
+        training(ctx, args, drv)
     return 0
 
 

@@ -5,7 +5,8 @@ per-layer metric is a file of its own, found by the name BENCHMARK.json
 gives it:
 
   bench/configs/<config>.json     sizes, source, reduced/assumed, and
-                                  `kind`: the runner that runs it
+                                  `kind`: the runner that runs it,
+                                  bench/harness/<kind>.py
   bench/traffic/<traffic>.json    the mix (lengths, rates, rounds) and
                                   the engine settings it is served with
   bench/limits/<cell>.json        the limits of the correctness check
@@ -13,15 +14,13 @@ gives it:
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
 from types import SimpleNamespace
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# the runner of each configuration kind: a module of bench/harness
-RUNNERS = {"dense_lm_serve": "serve", "nin_ec": "ec"}
 
 
 def _json(path: str) -> dict:
@@ -37,7 +36,7 @@ def load(root: str, workload: str) -> SimpleNamespace:
                        f"(have {sorted(cells)})")
     cell = cells[workload]
     config = _json(os.path.join(BENCH, "configs", cell["config"] + ".json"))
-    if config.get("kind") not in RUNNERS:
+    if not has_runner(config.get("kind")):
         raise ValueError(f"configuration {cell['config']} has no known "
                          f"kind ({config.get('kind')!r})")
     mix = _json(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
@@ -45,6 +44,12 @@ def load(root: str, workload: str) -> SimpleNamespace:
     return SimpleNamespace(spec=spec, cell=cell, config=config, mix=mix,
                            limits=limits["limits"], trace_dir=None,
                            seed=0, seconds=0.0, t_start=0.0)
+
+
+def has_runner(kind) -> bool:
+    """Is there a runner module bench/harness/<kind>.py?"""
+    return (isinstance(kind, str) and kind.isidentifier()
+            and importlib.util.find_spec("harness." + kind) is not None)
 
 
 def use_compile_cache(root: str) -> None:
@@ -61,8 +66,10 @@ def use_compile_cache(root: str) -> None:
 
 
 def runner(ctx):
-    import importlib
-    return importlib.import_module("harness." + RUNNERS[ctx.config["kind"]])
+    """The configuration kind's runner module: run(ctx) -> result,
+    passed(checks), and CALIBRATE, "serving" or "training", the way
+    bench/calibrate.py reads its limits."""
+    return importlib.import_module("harness." + ctx.config["kind"])
 
 
 def metrics_of(spec: dict, cell: str, kind: str) -> list:
@@ -126,7 +133,8 @@ def run(ctx, devs) -> dict:
             "failed": int(res["failed"]), "metrics": metrics, "device": dev,
             "run": {k: res[k] for k in ("window_s", "late_max_s",
                                         "late_mean_s", "steps_in_window",
-                                        "pool_bytes_per_token")
+                                        "pool_bytes_per_token",
+                                        "round_losses", "reference_s")
                     if k in res}}
     if breakdown is not None:
         line["breakdown"] = breakdown

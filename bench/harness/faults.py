@@ -6,10 +6,11 @@ Serving (patches of the program, undone by the returned function):
   state_unchanged   the decode step returns the KV pool it was given
   token_altered     every sampled token is moved to the next id
 
-EC training (planted in the trainer's recorded calls):
+EC training (planted in the trainer's recorded calls, any EC kind):
   state_unchanged     the local step returns its state as it came
   half_batch          the local step's loss is the mean over the first
-                      half of the batch
+                      half of the batch (of one sequence: of its
+                      positions)
   loss_altered        the loss the local step returns is 1% off
   relabel_one_member  the relabel takes one member's distribution, not
                       the ensemble's
@@ -45,19 +46,17 @@ EC_FAULTS = ("state_unchanged", "half_batch", "loss_altered",
              "relabel_one_member", "lam_zero")
 
 
-def plant_ec(name: str, config: dict, recs: dict):
-    """Plant a fault in the trainer's recorded calls (bench/harness/ec.py
-    recorders): the local step built as the trainer builds it (not
-    donating) with the fault in it, the relabel's quorum, or the lambda
-    the distillation step gets."""
+def plant_ec(name: str, tr, recs: dict):
+    """Plant a fault in the trainer's recorded calls (the recorders of
+    bench/harness/ecround.py): the local step built from the trainer's
+    own program configuration and optimizer, with the fault in it, the
+    relabel's quorum, or the lambda the distillation step gets."""
     import jax
     import jax.numpy as jnp
 
-    from repro.configs import registry
-    from repro.optim import sgd_momentum
     from repro.runtime import steps
     if name == "relabel_one_member":
-        one = jnp.zeros((config["members"],), jnp.float32).at[0].set(1.0)
+        one = jnp.zeros((tr.K,), jnp.float32).at[0].set(1.0)
         recs["relabel"].quorum = one
         return
     if name == "lam_zero":
@@ -65,22 +64,28 @@ def plant_ec(name: str, config: dict, recs: dict):
         orig = dis.step
         dis.fault = lambda s, b, ps, lam: orig(s, b, ps, jnp.zeros_like(lam))
         return
-    cfg = registry.get_config(config["arch"]).with_(
-        vocab_size=config["n_classes"])
-    opt = sgd_momentum(config["lr"], momentum=config["momentum"])
-    step = steps.make_local_step(cfg, opt)
+    step = steps.make_local_step(tr.cfg, tr.opt)
     if name == "state_unchanged":
-        fn = jax.jit(lambda s, b: (s, step(s, b, None, 0.0)[1]))
+        def f(s, b):
+            return s, step(s, b, None, 0.0)[1]
     elif name == "half_batch":
         def half(b):
-            n = b["labels"].shape[1] // 2
-            return jax.tree.map(lambda a: a[:, :n], b)
-        fn = jax.jit(lambda s, b: step(s, half(b), None, 0.0))
+            n = b["labels"].shape[1]
+            if n > 1:
+                return jax.tree.map(lambda a: a[:, :n // 2], b)
+            # one sequence a member: a token-level loss's batch is its
+            # positions, so the first half of them
+            t = b["labels"].shape[2] // 2
+            return jax.tree.map(lambda a: a[:, :, :t], b)
+
+        def f(s, b):
+            return step(s, half(b), None, 0.0)
     elif name == "loss_altered":
         def f(s, b):
             s, loss = step(s, b, None, 0.0)
             return s, loss * 1.01
-        fn = jax.jit(f)
     else:
         raise ValueError(f"no EC fault {name!r}")
-    recs["local"].fault = fn
+    # donating, as the trainer's step does: a state the size of a
+    # language model's does not fit the chip twice
+    recs["local"].fault = jax.jit(f, donate_argnums=(0,))

@@ -55,6 +55,16 @@ def prefill_flops(m: dict, prompt_len: int) -> float:
                            + lm_attn_flops(m, ctx_sum) + lm_head_flops(m))
 
 
+def lm_train_flops_per_token(m: dict, seq_len: int) -> float:
+    """One member, one token of a training sequence of `seq_len`,
+    forward and backward: 3x the forward (the backward computes the
+    gradients of inputs and of weights), where the forward is the
+    projections, the MLP, causal attention over a mean (seq_len + 1) / 2
+    positions and the LM head.  What remat recomputes is not counted."""
+    return 3 * (lm_matmul_flops_per_token(m)
+                + lm_attn_flops(m, (seq_len + 1) / 2) + lm_head_flops(m))
+
+
 def kv_bytes_per_token(m: dict, kv_dtype: str) -> float:
     """Paged-pool bytes one token occupies in one layer of one member:
     K and V planes plus their scales when quantized."""

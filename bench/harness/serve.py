@@ -25,6 +25,8 @@ import numpy as np
 
 from harness import flops, stats, traffic, xtrace
 
+CALIBRATE = "serving"
+
 LOADGEN = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "loadgen.py")
 

@@ -172,3 +172,19 @@ def image_data(m: dict, seed: int, n: int, test: bool = False) -> dict:
                 "labels": labels.astype(jnp.int32)}
 
     return make(jax.random.fold_in(base_key(seed), DATA_LEAF + int(test)))
+
+
+def token_data(m: dict, seed: int, n: int, test: bool = False) -> dict:
+    """{tokens, labels} (K, n, seq_len) int32 (or one (n, seq_len) set
+    when test=True): sequences of seq_len + 1 ids drawn uniformly from
+    the configuration's vocabulary, labels the next id, made on the
+    device."""
+    lead = (n,) if test else (m["members"], n)
+
+    @jax.jit
+    def make(key):
+        seq = jax.random.randint(key, lead + (m["seq_len"] + 1,), 0,
+                                 m["vocab_size"], jnp.int32)
+        return {"tokens": seq[..., :-1], "labels": seq[..., 1:]}
+
+    return make(jax.random.fold_in(base_key(seed), DATA_LEAF + 2 + int(test)))

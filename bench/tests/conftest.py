@@ -54,6 +54,19 @@ TINY_NIN = {
     "relabel_fraction": 0.25, "lr": 0.05, "momentum": 0.9, "l2": 1e-4,
     "bias_std": 0.05}
 
+# the LM EC round at a size the CPU holds, in float32: the CPU computes
+# the program's float32 as the reference does, so a sound run reads at
+# round-off and the test holds the harness (each fault caught, the
+# control failing) to the cell's own limits, which bfloat16 runs on the
+# chip set; lr 0.5 parts the members at this size, as the cell's do
+TINY_LM_EC = dict(
+    TINY_LM, kind="lm_ec", n_layers=2, d_model=64, n_heads=2, n_kv_heads=2,
+    head_dim=32, d_ff=128, vocab_size=256, dtype="float32", seq_len=32,
+    batch=1, per_member=4, tau_steps=4, p_steps=2, lam=0.5,
+    relabel_fraction=0.75, label_mode="topk", top_m=8, lr=0.5,
+    momentum=0.9)
+LM_MIX = {"kind": "ec_rounds", "warm_rounds": 2, "test_sequences": 2}
+
 
 def ctx(config: dict, mix: dict, cell: str, seed: int, seconds: float,
         **limit_overrides) -> SimpleNamespace:
